@@ -442,10 +442,15 @@ def bench_mesh(cfg, params, *, governor, nreq, out_len, mesh):
 
 def _parse_mesh(spec: str):
     """'dp,tp' -> tuple; 'auto' picks the widest shape the visible devices
-    support (both axes when 8 are forced, data-only on 2, degenerate on 1)."""
+    support (both axes when 8 are forced, data-only on 2).  One device has
+    no mesh to measure: 'auto' refuses it instead of timing a (1,1) mesh."""
     if spec == "auto":
         d = len(jax.devices())
-        return (2, 4) if d >= 8 else (2, 1) if d >= 2 else (1, 1)
+        if d < 2:
+            raise SystemExit(
+                "--mesh auto needs at least 2 devices, found 1 (on CPU set "
+                "XLA_FLAGS=--xla_force_host_platform_device_count=8)")
+        return (2, 4) if d >= 8 else (2, 1)
     dp, tp = (int(x) for x in spec.split(","))
     return dp, tp
 
@@ -624,6 +629,8 @@ def main():
                     help="also write rows + run config as a JSON document "
                          "(the BENCH_*.json baseline format)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     batches = tuple(int(x) for x in args.batches.split(","))
     # --governors "" runs only the standalone scenarios (e.g. --cluster)
     governors = tuple(g for g in args.governors.split(",") if g)

@@ -10,6 +10,9 @@ Design:
   array (B, Sk) (slot -> absolute position, -1 for unfilled); masking is
   `0 <= k_pos <= q_pos` plus an optional sliding window — identical
   semantics to models.kvcache.
+* TPU tiling: ``q_pos`` rides in as a scalar-prefetch operand (a (1,) VMEM
+  block of a (B,) array is not tileable), and ``k_pos`` is viewed as
+  (B, 1, Sk) so its (1, block_k) block spans the full middle dim.
 * fp32 accumulation; bf16 cache reads.
 """
 from __future__ import annotations
@@ -27,6 +30,7 @@ NEG_INF = -1e30
 def _kernel(qpos_ref, q_ref, k_ref, v_ref, kpos_ref, o_ref,
             acc_ref, m_ref, l_ref, *, scale: float, window: int,
             block_k: int, num_k_blocks: int):
+    b = pl.program_id(0)
     j = pl.program_id(2)
 
     @pl.when(j == 0)
@@ -38,20 +42,20 @@ def _kernel(qpos_ref, q_ref, k_ref, v_ref, kpos_ref, o_ref,
     q = q_ref[0, 0].astype(jnp.float32)              # (G, hd)
     k = k_ref[0, 0].astype(jnp.float32)              # (bk, hd)
     v = v_ref[0, 0].astype(jnp.float32)
-    kpos = kpos_ref[0]                               # (bk,)
-    qpos = qpos_ref[0]                               # scalar int32
+    kpos = kpos_ref[0]                               # (1, bk)
+    qpos = qpos_ref[b]                               # scalar int32 (SMEM)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     valid = jnp.logical_and(kpos >= 0, kpos <= qpos)
     if window:
         valid = jnp.logical_and(valid, kpos > qpos - window)
-    s = jnp.where(valid[None, :], s, NEG_INF)        # (G, bk)
+    s = jnp.where(valid, s, NEG_INF)                 # (G, bk)
 
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     p = jnp.exp(s - m_new)
-    p = jnp.where(valid[None, :], p, 0.0)
+    p = jnp.where(valid, p, 0.0)
     corr = jnp.exp(m_prev - m_new)
     l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
     acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
@@ -83,23 +87,29 @@ def decode_attention(q, k, v, k_pos, q_pos, *, window: int = 0,
     kernel = functools.partial(_kernel, scale=scale, window=window,
                                block_k=block_k, num_k_blocks=nk)
 
-    out = pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,                       # q_pos
         grid=(B, KH, nk),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, h, j: (b,)),                 # q_pos
-            pl.BlockSpec((1, 1, G, hd), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, block_k, hd), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, block_k, hd), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((1, block_k), lambda b, h, j: (b, j)),       # k_pos
+            pl.BlockSpec((1, 1, G, hd), lambda b, h, j, qp: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, block_k, hd),
+                         lambda b, h, j, qp: (b, h, j, 0)),
+            pl.BlockSpec((1, 1, block_k, hd),
+                         lambda b, h, j, qp: (b, h, j, 0)),
+            pl.BlockSpec((1, 1, block_k), lambda b, h, j, qp: (b, 0, j)),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, hd), lambda b, h, j: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, KH, G, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, G, hd),
+                               lambda b, h, j, qp: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((G, hd), jnp.float32),
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, 1), jnp.float32),
         ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, KH, G, hd), q.dtype),
         interpret=interpret,
-    )(q_pos, qg, k, v, k_pos)
+    )(q_pos, qg, k, v, k_pos.reshape(B, 1, Sk))
     return out.reshape(B, Hq, hd)

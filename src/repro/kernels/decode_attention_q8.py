@@ -3,7 +3,8 @@
 Kernel-level counterpart of the ``kv_quant`` serving feature (§Perf H1):
 K/V arrive as int8 with per-(token, head) f32 scales and are dequantized
 *inside* the kernel after the VMEM load — so HBM traffic is halved while
-the MXU still computes in f32.  Grid/accumulator structure matches
+the MXU still computes in f32.  Grid/accumulator structure and the
+scalar-prefetched ``q_pos`` / (B, 1, Sk) ``k_pos`` operands match
 decode_attention.py.
 """
 from __future__ import annotations
@@ -21,6 +22,7 @@ NEG_INF = -1e30
 def _kernel(qpos_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, kpos_ref, o_ref,
             acc_ref, m_ref, l_ref, *, scale: float, window: int,
             num_k_blocks: int):
+    b = pl.program_id(0)
     j = pl.program_id(2)
 
     @pl.when(j == 0)
@@ -33,20 +35,20 @@ def _kernel(qpos_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, kpos_ref, o_ref,
     # dequantize in-register after the (cheap) int8 VMEM load
     k = k_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0]     # (bk, hd)
     v = v_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0]
-    kpos = kpos_ref[0]
-    qpos = qpos_ref[0]
+    kpos = kpos_ref[0]                                     # (1, bk)
+    qpos = qpos_ref[b]                                     # SMEM scalar
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     valid = jnp.logical_and(kpos >= 0, kpos <= qpos)
     if window:
         valid = jnp.logical_and(valid, kpos > qpos - window)
-    s = jnp.where(valid[None, :], s, NEG_INF)
+    s = jnp.where(valid, s, NEG_INF)
 
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     p = jnp.exp(s - m_new)
-    p = jnp.where(valid[None, :], p, 0.0)
+    p = jnp.where(valid, p, 0.0)
     corr = jnp.exp(m_prev - m_new)
     l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
     acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
@@ -77,25 +79,30 @@ def decode_attention_q8(q, k_q8, k_scale, v_q8, v_scale, k_pos, q_pos, *,
 
     kernel = functools.partial(_kernel, scale=scale, window=window,
                                num_k_blocks=nk)
-    out = pl.pallas_call(
-        kernel,
+    kv_blk = pl.BlockSpec((1, 1, block_k, hd),
+                          lambda b, h, j, qp: (b, h, j, 0))
+    sc_blk = pl.BlockSpec((1, 1, block_k, 1),
+                          lambda b, h, j, qp: (b, h, j, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,                             # q_pos
         grid=(B, KH, nk),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, h, j: (b,)),
-            pl.BlockSpec((1, 1, G, hd), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, block_k, hd), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, block_k, 1), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, block_k, hd), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, block_k, 1), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((1, block_k), lambda b, h, j: (b, j)),
+            pl.BlockSpec((1, 1, G, hd), lambda b, h, j, qp: (b, h, 0, 0)),
+            kv_blk, sc_blk, kv_blk, sc_blk,
+            pl.BlockSpec((1, 1, block_k), lambda b, h, j, qp: (b, 0, j)),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, hd), lambda b, h, j: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, KH, G, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, G, hd),
+                               lambda b, h, j, qp: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((G, hd), jnp.float32),
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, 1), jnp.float32),
         ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, KH, G, hd), q.dtype),
         interpret=interpret,
-    )(q_pos, qg, k_q8, k_scale, v_q8, v_scale, k_pos)
+    )(q_pos, qg, k_q8, k_scale, v_q8, v_scale, k_pos.reshape(B, 1, Sk))
     return out.reshape(B, Hq, hd)

@@ -1,8 +1,7 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On this CPU container the kernels run in interpret mode (the body executes
-as jnp ops); on a real TPU set ``interpret=False`` (default decided by the
-platform).  Layout conventions match the model code: (B, S, H, hd).
+Off a TPU the kernels run in interpret mode (the body executes as jnp ops);
+on a TPU they lower to Mosaic (the default is decided by the platform).  Layout conventions match the model code: (B, S, H, hd).
 """
 from __future__ import annotations
 

@@ -11,14 +11,9 @@ import jax
 
 
 def _mk_mesh(shape, axes, devices):
-    """``jax.make_mesh`` across jax versions: ``axis_types`` (explicit Auto
-    partitioning) only exists on newer releases — older ones are Auto-only,
-    so dropping the kwarg is behavior-preserving, not a downgrade."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes, devices=devices,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes, devices=devices)
+    """``jax.make_mesh`` with every axis in Auto (GSPMD) partitioning."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -4,6 +4,8 @@ the ``serving.api.Server`` front door, fed by a synthetic stream or a named
 trace, and print the shared typed ``ServingReport``.
 
   PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b --requests 16
+  # published widths (28 layers, d_model 1536 for qwen2-1.5b) — a TPU job
+  PYTHONPATH=src python -m repro.launch.serve --width full --paged
   PYTHONPATH=src python -m repro.launch.serve --governor defaultnv --paged
   PYTHONPATH=src python -m repro.launch.serve --cluster --trace azure_code8
   PYTHONPATH=src python -m repro.launch.serve --no-chunked --requests 8
@@ -29,10 +31,11 @@ from repro.configs import get_config
 from repro.core import (AlertEngine, AlertRule, EnergyLedger,
                         MetricsRegistry, SamplingParams, SLOConfig, Tracer,
                         verify_conservation)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import EngineConfig, Server, ServingCluster, ServingEngine
 
 
-def build_backend(args, full, smoke):
+def build_backend(args, full, model):
     mesh = None
     if args.mesh:
         try:
@@ -47,9 +50,9 @@ def build_backend(args, full, smoke):
                         mesh=mesh)
     if args.cluster:
         # paged slot-native plane is forced by the cluster (KV handoff)
-        return ServingCluster(smoke, n_prefill=1, n_decode=1,
+        return ServingCluster(model, n_prefill=1, n_decode=1,
                               plant_cfg=full, ecfg=ecfg)
-    return ServingEngine(smoke, plant_cfg=full, ecfg=ecfg)
+    return ServingEngine(model, plant_cfg=full, ecfg=ecfg)
 
 
 def sampling_for(args, i: int, max_tokens: int) -> SamplingParams:
@@ -167,6 +170,11 @@ class Dashboard:
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-1.5b")
+    ap.add_argument("--width", default="smoke", choices=["smoke", "full"],
+                    help="model that produces the tokens: the reduced "
+                         "ModelConfig.smoke() (default; CPU-sized) or the "
+                         "published config (full width and depth).  The "
+                         "plant model always accounts the published config")
     ap.add_argument("--requests", type=int, default=12)
     ap.add_argument("--governor", default="greenllm",
                     choices=["greenllm", "defaultnv"])
@@ -234,8 +242,9 @@ def main(argv=None):
                          "are audited against the timeline at exit")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     full = get_config(args.arch)
-    smoke = full.smoke()
+    model = full if args.width == "full" else full.smoke()
     metrics = MetricsRegistry(snapshot_min_dt=0.005) \
         if args.metrics_out or args.dashboard > 0 or args.alerts else None
     tracer = Tracer() if args.trace_out else None
@@ -244,19 +253,19 @@ def main(argv=None):
                          tracer=tracer) if args.alerts else None
     on_event = Dashboard(args.dashboard, metrics, alerts=alerts) \
         if args.dashboard > 0 else None
-    server = Server(build_backend(args, full, smoke), on_event=on_event,
+    server = Server(build_backend(args, full, model), on_event=on_event,
                     metrics=metrics, tracer=tracer, ledger=ledger,
                     alerts=alerts)
     n = 0
-    for arrival, prompt, max_tokens in workload(args, smoke.vocab_size):
+    for arrival, prompt, max_tokens in workload(args, model.vocab_size):
         server.submit(prompt, sampling_for(args, n, max_tokens),
                       arrival=arrival)
         n += 1
     rep = server.run()
     plane = "cluster(1p+1d)" if args.cluster else \
         ("engine/paged" if args.paged else "engine")
-    print(f"arch={args.arch} governor={args.governor} plane={plane} "
-          f"trace={args.trace} requests={n}")
+    print(f"arch={args.arch} width={args.width} governor={args.governor} "
+          f"plane={plane} trace={args.trace} requests={n}")
     print(rep.summary())
     for row in rep.replicas:
         print(f"  {row.name:10s} {row.role:9s} "

@@ -400,11 +400,12 @@ def cache_row_extract(cache: Dict, slot: int):
     return {k: v[:, slot:slot + 1] for k, v in cache.items()}
 
 
-def cache_row_insert(cache: Dict, row: Dict, slot: int):
+def cache_row_insert(cache: Dict, row: Dict, slot):
     """Splice an extracted row (``cache_row_extract`` output) into batch row
-    ``slot`` of another stacked dense cache dict."""
-    return {k: cache[k].at[:, slot:slot + 1].set(row[k].astype(cache[k].dtype))
-            for k in cache}
+    ``slot`` (int or traced) of another stacked dense cache dict."""
+    return {k: jax.lax.dynamic_update_slice_in_dim(
+        cache[k], row[k].astype(cache[k].dtype), slot, axis=1)
+        for k in cache}
 
 
 def state_row_slot(batch_cache, slot):

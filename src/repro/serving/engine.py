@@ -50,11 +50,11 @@ Data-plane design (the hot path):
   rewrite.  Pool pressure can preempt streams in *either* phase (decoding or
   mid-chunked-prefill), youngest-first, with recompute-on-resume.
 
-On this CPU container the engine runs reduced models; *virtual time* for
-SLO/energy accounting comes from the calibrated plant model (wall-clock CPU
-time of a smoke-scale model says nothing about an A100/TPU), while the token
-*values* are produced by the real network.  On real hardware, set
-``use_wall_clock=True`` to account with measured per-block latencies instead.
+*Virtual time* for SLO/energy accounting comes from the calibrated plant
+model on every backend, while the token *values* are produced by the real
+network — the reduced ``.smoke()`` models on CPU, published widths on a TPU
+(``chip_smoke.py``).  ``use_wall_clock=True`` accounts decode blocks with
+measured per-block latencies instead.
 
 ``EngineConfig(slot_native=False)`` keeps the pre-slot data plane (per-request
 prefill + full-cache splice, per-step host sync, batch-wide ``max(pos)``) as a
@@ -66,7 +66,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 import time
-import warnings
 from typing import Dict, List, Optional
 
 import jax
@@ -94,11 +93,6 @@ from repro.sim.profiling import profile_decode_table
 from repro.core.hardware import HardwareProfile, A100_SXM4_40G
 from .pager import PageAllocator
 from .prefix_cache import PrefixCache
-
-# CPU XLA has no buffer donation; the jitted step is still correct, so keep
-# the log quiet on smoke runs (donation engages on TPU/GPU).
-warnings.filterwarnings(
-    "ignore", message="Some donated buffers were not usable")
 
 
 # -- jitted kernels (module level: JAX's global jit cache shares compiles
@@ -302,6 +296,21 @@ def _page_copy_kernel(caches, src, dst):
     out = []
     for stage in caches:
         out.append(tuple(paged_page_copy(d, src, dst) for d in stage))
+    return out
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _import_kernel(caches, payloads, chain, slot):
+    """Scatter a migrated stream's page-chain K/V (paged leaves) and batch
+    rows (bounded rings, recurrent state) into the donated caches: adoption
+    writes O(context) in place instead of copying every pool leaf.  One
+    compile per chain length."""
+    out = []
+    for stage, pstage in zip(caches, payloads):
+        out.append(tuple(
+            (d if p is None else paged_chain_insert(d, p, chain))
+            if is_paged(d) else cache_row_insert(d, p, slot)
+            for d, p in zip(stage, pstage)))
     return out
 
 
@@ -1554,17 +1563,11 @@ class ServingEngine:
             if chain is None:
                 return False
         self.free_slots.pop(0)
-        caches = []
-        for stage, hstage in zip(self.caches, ho.blocks):
-            sblocks = []
-            for d, (kind, payload) in zip(stage, hstage):
-                if kind == "pages":
-                    sblocks.append(paged_chain_insert(d, payload, chain)
-                                   if payload is not None else d)
-                else:
-                    sblocks.append(cache_row_insert(d, payload, slot))
-            caches.append(tuple(sblocks))
-        self.caches = self._pin_caches(caches)
+        payloads = [tuple(p for _, p in hstage) for hstage in ho.blocks]
+        self.caches = self._pin_caches(_import_kernel(
+            self.caches, payloads,
+            jnp.asarray(chain, jnp.int32) if chain else None,
+            jnp.asarray(slot, jnp.int32)))
         self._tok = self._row_pin(self._tok.at[slot].set(ho.last_token))
         self._pos = self._row_pin(self._pos.at[slot].set(ho.pos))
         # the RNG lane and the exporter-resolved sampling config travel with

@@ -51,7 +51,6 @@ def test_mini_mesh_lowering_subprocess():
         from repro.configs import get_config
         from repro.launch import shardings as SH
         from repro.launch.specs import InputShape, build_step
-        # version-compat mesh construction (axis_types only on newer jax)
         from repro.launch.mesh import make_debug_mesh
         mesh = make_debug_mesh(model=4, data=2)
         from repro.launch.specs import build_train
