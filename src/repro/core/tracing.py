@@ -1,6 +1,7 @@
-"""Request-lifecycle tracing + DVFS decision logs on the virtual clock.
+"""The engine's two trace records, each on its own clock.
 
-Two record types, one ring-buffered collector:
+**Lifecycle/DVFS log, on the virtual (energy) clock** — ``Tracer``, one
+ring-buffered collector of two record types:
 
 * ``Span`` — one interval (or instant, ``end == start``) in a request's
   life: ``submit → queue → prefill`` chunks ``→ decode_block``s ``→
@@ -12,16 +13,30 @@ Two record types, one ring-buffered collector:
   TBT, occupancy, queue state), the chosen frequency, and a **reason
   code**, so "why did the clock move?" is answerable from the log alone.
 
-Timestamps are virtual-clock seconds (the engines' energy/SLO clock), so
-traces are deterministic and replayable.  The collector is a bounded
-``deque`` — a long-lived server never grows without bound; ``dropped``
-counts evictions.  Writers: Chrome trace-event JSON (load in
-``chrome://tracing`` / Perfetto; replicas become processes, requests
-become threads) and a JSONL form that round-trips via ``read_jsonl``.
+Timestamps are virtual-clock seconds (the plant model's energy/SLO clock),
+so these traces are deterministic and replayable; they say nothing about
+where wall time or device time went.  The collector is a bounded ``deque``
+— a long-lived server never grows without bound; ``dropped`` counts
+evictions.  Writers: Chrome trace-event JSON (load in ``chrome://tracing``
+/ Perfetto; replicas become processes, requests become threads) and a
+JSONL form that round-trips via ``read_jsonl``.  Like the metrics
+registry, it rides existing host-sync points: every emission site is
+guarded by ``tracer is not None`` and records host floats the engine
+already had — no device syncs, zero overhead when off.
 
-Like the metrics registry, tracing rides existing host-sync points: every
-emission site is guarded by ``tracer is not None`` and records host floats
-the engine already had — no device syncs, zero overhead when off.
+**Host spans, on the profiler's clock** — ``host_span`` opens a
+``jax.profiler.TraceAnnotation`` (a TraceMe) around one of the engine's
+layer boundaries (``engine.step``, ``engine.admit``, ``engine.prefill``,
+``engine.chunk``, ``engine.grow_pages``, ``engine.dispatch``,
+``engine.drain``, ``engine.bookkeep``); its keyword attributes become event
+stats.  They land in the same profile (``.xplane.pb``) as the device's op
+and program events, on the same clock, so device idle time can be charged
+to the host work that covers it.  With no profile active a span records
+nothing and costs one small object.  The jitted programs carry the matching
+device side as ``jax.named_scope``s (``decode_block``, ``prefill``,
+``prefill_chunk``, ``kv_cache``, ``attention``, ``sampler``), which reach
+each HLO op's ``op_name`` metadata.  Capture both with
+``jax.profiler.trace(dir)`` around the serving loop.
 """
 from __future__ import annotations
 
@@ -29,6 +44,16 @@ import json
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Dict, Iterable, List, Optional
+
+import jax
+
+
+def host_span(name: str, **attrs) -> jax.profiler.TraceAnnotation:
+    """A profiler host span named ``name`` with ``attrs`` as event stats;
+    use as ``with host_span(...) as sp:``, and ``sp.set_metadata(**more)``
+    adds stats known only at its end.  Records nothing unless a profile is
+    being taken."""
+    return jax.profiler.TraceAnnotation(name, **attrs)
 
 
 @dataclass(frozen=True)

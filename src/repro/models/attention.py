@@ -62,28 +62,29 @@ def attention(
     q_chunk: int = 1024,
     unroll: bool = False,
 ) -> jax.Array:
-    B, Sq, Hq, hd = q.shape
-    KH = k.shape[2]
-    scale = scale if scale is not None else hd ** -0.5
-    if Hq != KH:
-        rep = Hq // KH
-        k = jnp.repeat(k, rep, axis=2)
-        v = jnp.repeat(v, rep, axis=2)
+    with jax.named_scope("attention"):
+        B, Sq, Hq, hd = q.shape
+        KH = k.shape[2]
+        scale = scale if scale is not None else hd ** -0.5
+        if Hq != KH:
+            rep = Hq // KH
+            k = jnp.repeat(k, rep, axis=2)
+            v = jnp.repeat(v, rep, axis=2)
 
-    if Sq <= q_chunk or Sq % q_chunk != 0:
-        return _attend_block(q, k, v, q_pos, k_pos,
-                             window=window, softcap=softcap, scale=scale)
+        if Sq <= q_chunk or Sq % q_chunk != 0:
+            return _attend_block(q, k, v, q_pos, k_pos,
+                                 window=window, softcap=softcap, scale=scale)
 
-    nc = Sq // q_chunk
-    qc = q.reshape(B, nc, q_chunk, Hq, hd).swapaxes(0, 1)        # (nc,B,qc,H,hd)
-    pc = q_pos.reshape(B, nc, q_chunk).swapaxes(0, 1)            # (nc,B,qc)
+        nc = Sq // q_chunk
+        qc = q.reshape(B, nc, q_chunk, Hq, hd).swapaxes(0, 1)        # (nc,B,qc,H,hd)
+        pc = q_pos.reshape(B, nc, q_chunk).swapaxes(0, 1)            # (nc,B,qc)
 
-    @jax.checkpoint
-    def body(_, xs):
-        qi, pi = xs
-        o = _attend_block(qi, k, v, pi, k_pos,
-                          window=window, softcap=softcap, scale=scale)
-        return _, o
+        @jax.checkpoint
+        def body(_, xs):
+            qi, pi = xs
+            o = _attend_block(qi, k, v, pi, k_pos,
+                              window=window, softcap=softcap, scale=scale)
+            return _, o
 
-    _, out = jax.lax.scan(body, None, (qc, pc), unroll=unroll)
-    return out.swapaxes(0, 1).reshape(B, Sq, Hq, hd)
+        _, out = jax.lax.scan(body, None, (qc, pc), unroll=unroll)
+        return out.swapaxes(0, 1).reshape(B, Sq, Hq, hd)

@@ -271,15 +271,18 @@ def _apply_attn(cfg: ModelConfig, p, x, kind, *, mode, positions, cache, pos,
         # ctx-bucketed page chain back as a dense (B, n_pages*ps) context
         pos_v = jnp.broadcast_to(jnp.atleast_1d(jnp.asarray(pos, jnp.int32)),
                                  (B,))
-        new_cache = KV.paged_cache_write_decode(cache, k, v, pos_v, page_table)
-        k_att, v_att = KV.paged_cache_kv_arrays(new_cache, page_table, q.dtype)
-        # serving mesh: the pool's page axis is data-sharded while the
-        # gathered per-row context is batch-sharded — constrain the gather
-        # output so GSPMD routes pages once instead of replicating the pool
-        # into every shard's gather (guidance only; rows are independent, so
-        # placement cannot change the bits)
-        k_att = shd.cs(k_att, "b", None, None, None)
-        v_att = shd.cs(v_att, "b", None, None, None)
+        with jax.named_scope("kv_cache"):
+            new_cache = KV.paged_cache_write_decode(cache, k, v, pos_v,
+                                                    page_table)
+            k_att, v_att = KV.paged_cache_kv_arrays(new_cache, page_table,
+                                                    q.dtype)
+            # serving mesh: the pool's page axis is data-sharded while the
+            # gathered per-row context is batch-sharded — constrain the
+            # gather output so GSPMD routes pages once instead of
+            # replicating the pool into every shard's gather (guidance only;
+            # rows are independent, so placement cannot change the bits)
+            k_att = shd.cs(k_att, "b", None, None, None)
+            v_att = shd.cs(v_att, "b", None, None, None)
         k_pos = jnp.broadcast_to(
             KV.paged_key_positions(k_att.shape[1], pos_v + 1),
             (B, k_att.shape[1]))
@@ -287,8 +290,9 @@ def _apply_attn(cfg: ModelConfig, p, x, kind, *, mode, positions, cache, pos,
                         softcap=cfg.attn_softcap, scale=cfg.attn_scale,
                         unroll=cfg.unroll_scans)
     elif mode == "decode":
-        new_cache = KV.cache_write_decode(cache, k, v, pos)
-        k_full, v_full = KV.cache_kv_arrays(new_cache, q.dtype)
+        with jax.named_scope("kv_cache"):
+            new_cache = KV.cache_write_decode(cache, k, v, pos)
+            k_full, v_full = KV.cache_kv_arrays(new_cache, q.dtype)
         k_pos = KV.cache_key_positions(new_cache, pos, B)
         buf_len = k_full.shape[1]
         if window == 0 and buf_len < cfg.max_seq:
@@ -316,10 +320,12 @@ def _apply_attn(cfg: ModelConfig, p, x, kind, *, mode, positions, cache, pos,
         # prefill; ``pos`` is the chunk's start position (traced scalar).
         start = jnp.asarray(pos, jnp.int32)
         if KV.is_paged(cache):
-            pk, pv = KV.paged_cache_kv_arrays(cache, page_table, q.dtype)
+            with jax.named_scope("kv_cache"):
+                pk, pv = KV.paged_cache_kv_arrays(cache, page_table, q.dtype)
             past_pos = KV.paged_key_positions(pk.shape[1], start)
-            new_cache = KV.paged_cache_write_chunk(cache, k, v, page_table[0],
-                                                   start, length)
+            with jax.named_scope("kv_cache"):
+                new_cache = KV.paged_cache_write_chunk(
+                    cache, k, v, page_table[0], start, length)
         else:
             pk, pv = KV.cache_row_kv_arrays(cache, slot, q.dtype)
             past_pos = KV.ring_slot_positions(pk.shape[1], start)[None]

@@ -77,6 +77,7 @@ from repro.core import (CounterfactualPricer, DualLoopController,
                         SamplingParams, ServingReport, SLOConfig, StateEvent,
                         TokenEvent, build_report, make_router)
 from repro.core.telemetry import OccupancyMeter, TBTMeter
+from repro.core.tracing import host_span
 from repro.launch.shardings import (gather_replicated, make_serving_shard_ctx,
                                     named, serving_param_specs,
                                     shard_serving_caches)
@@ -148,10 +149,11 @@ def _sample_rows(sampled, logits, pos_next, keys, temps, topk, topp):
     temperature/top-k/top-p lanes when ``sampled`` (a host-known static:
     does any live row sample?), plain argmax otherwise — all-greedy blocks
     never pay for the sampler's sort."""
-    if sampled:
-        return sample_tokens_batched(logits, temps, topk, topp,
-                                     _row_subkeys(keys, pos_next))
-    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    with jax.named_scope("sampler"):
+        if sampled:
+            return sample_tokens_batched(logits, temps, topk, topp,
+                                         _row_subkeys(keys, pos_next))
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5),
@@ -175,22 +177,24 @@ def _decode_block_kernel(cfg, shd, ctx, k, max_len, sampled,
     every other operand stays sharded along the data axis only, so the
     sharded block is bit-identical to the single-device one.
     """
-    params = gather_replicated(params, shd.mesh)
-    sliced = _slice_caches(caches, ctx, max_len)
+    with jax.named_scope("decode_block"):
+        params = gather_replicated(params, shd.mesh)
+        sliced = _slice_caches(caches, ctx, max_len)
 
-    def body(carry, _):
-        tok, sl, pos = carry
-        logits, sl = decode_step(params, cfg, tok[:, None], sl, pos,
-                                 shd=shd, active=active)
-        nxt = _sample_rows(sampled, logits, pos + 1, keys, temps, topk, topp)
-        tok = jnp.where(active, nxt, tok)
-        pos = pos + active.astype(jnp.int32)
-        return (tok, sl, pos), tok
+        def body(carry, _):
+            tok, sl, pos = carry
+            logits, sl = decode_step(params, cfg, tok[:, None], sl, pos,
+                                     shd=shd, active=active)
+            nxt = _sample_rows(sampled, logits, pos + 1, keys, temps, topk,
+                               topp)
+            tok = jnp.where(active, nxt, tok)
+            pos = pos + active.astype(jnp.int32)
+            return (tok, sl, pos), tok
 
-    (tok, sliced, pos), toks = jax.lax.scan(
-        body, (tok, sliced, pos), None, length=k)
-    caches = _unslice_caches(caches, sliced, ctx, max_len)
-    return tok, caches, pos, toks
+        (tok, sliced, pos), toks = jax.lax.scan(
+            body, (tok, sliced, pos), None, length=k)
+        caches = _unslice_caches(caches, sliced, ctx, max_len)
+        return tok, caches, pos, toks
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 2, 3), donate_argnums=(6,))
@@ -209,20 +213,22 @@ def _paged_decode_block_kernel(cfg, shd, k, sampled, params, tok, caches, pt,
     cross-shard data movement, so tokens stay bit-identical to the
     single-device kernel.
     """
-    params = gather_replicated(params, shd.mesh)
+    with jax.named_scope("decode_block"):
+        params = gather_replicated(params, shd.mesh)
 
-    def body(carry, _):
-        tok, cs, pos = carry
-        logits, cs = decode_step(params, cfg, tok[:, None], cs, pos,
-                                 shd=shd, page_table=pt, active=active)
-        nxt = _sample_rows(sampled, logits, pos + 1, keys, temps, topk, topp)
-        tok = jnp.where(active, nxt, tok)
-        pos = pos + active.astype(jnp.int32)
-        return (tok, cs, pos), tok
+        def body(carry, _):
+            tok, cs, pos = carry
+            logits, cs = decode_step(params, cfg, tok[:, None], cs, pos,
+                                     shd=shd, page_table=pt, active=active)
+            nxt = _sample_rows(sampled, logits, pos + 1, keys, temps, topk,
+                               topp)
+            tok = jnp.where(active, nxt, tok)
+            pos = pos + active.astype(jnp.int32)
+            return (tok, cs, pos), tok
 
-    (tok, caches, pos), toks = jax.lax.scan(
-        body, (tok, caches, pos), None, length=k)
-    return tok, caches, pos, toks
+        (tok, caches, pos), toks = jax.lax.scan(
+            body, (tok, caches, pos), None, length=k)
+        return tok, caches, pos, toks
 
 
 @functools.partial(jax.jit, static_argnums=(0,))
@@ -243,16 +249,18 @@ def _prefill_kernel(cfg, shd, sampled, params, toks, length, caches, slot,
     ``toks``).  ``pt_row`` is the stream's (1, n_pages) page-table row for
     paged caches, or None.  The first token lands at position ``length``,
     so its draw subkey is ``fold_in(keys[slot], length)``."""
-    params = gather_replicated(params, shd.mesh)
-    logits, caches, _ = prefill_into_slot(params, cfg, toks, length, caches,
-                                          slot, shd=shd, page_table=pt_row)
-    L = jnp.asarray(length, jnp.int32)
-    ptok = _sample_rows(sampled, logits, L[None], _slot_row(keys, slot),
-                        _slot_row(temps, slot), _slot_row(topk, slot),
-                        _slot_row(topp, slot))[0]
-    tok = tok.at[slot].set(ptok)
-    pos = pos.at[slot].set(length)
-    return tok, caches, pos
+    with jax.named_scope("prefill"):
+        params = gather_replicated(params, shd.mesh)
+        logits, caches, _ = prefill_into_slot(params, cfg, toks, length,
+                                              caches, slot, shd=shd,
+                                              page_table=pt_row)
+        L = jnp.asarray(length, jnp.int32)
+        ptok = _sample_rows(sampled, logits, L[None], _slot_row(keys, slot),
+                            _slot_row(temps, slot), _slot_row(topk, slot),
+                            _slot_row(topp, slot))[0]
+        tok = tok.at[slot].set(ptok)
+        pos = pos.at[slot].set(length)
+        return tok, caches, pos
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 2), donate_argnums=(7,))
@@ -273,17 +281,18 @@ def _chunk_prefill_kernel(cfg, shd, sampled, params, toks, start, length,
     recompute-on-resume replay (which discards even the final draw in favor
     of ``resume_tok``) cannot perturb the stream's draw sequence.
     """
-    params = gather_replicated(params, shd.mesh)
-    logits, caches = prefill_chunk_into_slot(params, cfg, toks, start, length,
-                                             caches, slot, shd=shd,
-                                             page_table=pt_row)
-    end = jnp.asarray(start, jnp.int32) + jnp.asarray(length, jnp.int32)
-    ptok = _sample_rows(sampled, logits, end[None], _slot_row(keys, slot),
-                        _slot_row(temps, slot), _slot_row(topk, slot),
-                        _slot_row(topp, slot))[0]
-    tok = tok.at[slot].set(ptok)
-    pos = pos.at[slot].set(start + length)
-    return tok, caches, pos
+    with jax.named_scope("prefill_chunk"):
+        params = gather_replicated(params, shd.mesh)
+        logits, caches = prefill_chunk_into_slot(params, cfg, toks, start,
+                                                 length, caches, slot,
+                                                 shd=shd, page_table=pt_row)
+        end = jnp.asarray(start, jnp.int32) + jnp.asarray(length, jnp.int32)
+        ptok = _sample_rows(sampled, logits, end[None], _slot_row(keys, slot),
+                            _slot_row(temps, slot), _slot_row(topk, slot),
+                            _slot_row(topp, slot))[0]
+        tok = tok.at[slot].set(ptok)
+        pos = pos.at[slot].set(start + length)
+        return tok, caches, pos
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -1108,27 +1117,29 @@ class ServingEngine:
         bucket = next(b for b in self.buckets if b >= L)
         padded = np.zeros((1, bucket), np.int32)
         padded[0, :L] = prompt
-        pt_row = None
-        if self.pager is not None:
-            ok = self.pager.ensure(slot, L)      # gated by _admit
-            assert ok, "admission gate let an unallocatable prompt through"
-            pt_row = self._pt_rows(slot, bucket)
-        self._set_slot_sampling(slot, req)
-        self._tok, self.caches, self._pos = _prefill_kernel(
-            self.cfg, self._shd, bool(self._sampled_host[slot]),
-            self.params, jnp.asarray(padded), jnp.asarray(L, jnp.int32),
-            self.caches, jnp.asarray(slot, jnp.int32), pt_row,
-            self._tok, self._pos, self._keys, self._temps, self._topk,
-            self._topp)
-        t0 = self.vtime
-        self._account_prefill(req)
-        if self.tracer is not None:
-            self.tracer.span("prefill", req.rid, t0, self.vtime, self.name,
-                             tokens=L, bucket=bucket)
-        self._register_prefix(req, slot, L)
-        self._publish_metrics()
-        # one tiny host read per admission (the first sampled token id)
-        self._start_stream(req, slot, int(self._tok[slot]), L)
+        with host_span("engine.prefill", rid=req.rid, tokens=L,
+                       bucket=bucket):
+            pt_row = None
+            if self.pager is not None:
+                ok = self.pager.ensure(slot, L)      # gated by _admit
+                assert ok, "admission gate let an unallocatable prompt through"
+                pt_row = self._pt_rows(slot, bucket)
+            self._set_slot_sampling(slot, req)
+            self._tok, self.caches, self._pos = _prefill_kernel(
+                self.cfg, self._shd, bool(self._sampled_host[slot]),
+                self.params, jnp.asarray(padded), jnp.asarray(L, jnp.int32),
+                self.caches, jnp.asarray(slot, jnp.int32), pt_row,
+                self._tok, self._pos, self._keys, self._temps, self._topk,
+                self._topp)
+            t0 = self.vtime
+            self._account_prefill(req)
+            if self.tracer is not None:
+                self.tracer.span("prefill", req.rid, t0, self.vtime,
+                                 self.name, tokens=L, bucket=bucket)
+            self._register_prefix(req, slot, L)
+            self._publish_metrics()
+            # one tiny host read per admission (the first sampled token id)
+            self._start_stream(req, slot, int(self._tok[slot]), L)
 
     def _admit_legacy(self, req: Request, slot: int):
         """Reference path: per-request prefill + host-side batch-cache splice.
@@ -1161,6 +1172,13 @@ class ServingEngine:
         self._start_stream(req, slot, tok, len(req.prompt))
 
     def _admit(self):
+        with host_span("engine.admit") as span:
+            span.set_metadata(admitted=self._admit_fifo())
+
+    def _admit_fifo(self) -> int:
+        """Admit arrived requests from the FIFO head while slots and pages
+        allow; returns how many were admitted."""
+        admitted = 0
         while self.pending and self.free_slots:
             req = self.pending[0]
             if max(req.arrival, req.not_before) > self.vtime + 1e-12:
@@ -1201,6 +1219,7 @@ class ServingEngine:
                                  max(req.arrival, req.not_before),
                                  self.vtime, self.name, slot=slot,
                                  resume=resume)
+            admitted += 1
             if not self.ecfg.slot_native:
                 self._admit_legacy(req, slot)
             elif hit_tok or resume or len(ctx_toks) > self.buckets[-1]:
@@ -1211,6 +1230,7 @@ class ServingEngine:
                     self._admit_legacy(req, slot)
             else:
                 self._admit_slot(req, slot)
+        return admitted
 
     def _start_chunked(self, req: Request, slot: int, ctx_toks: np.ndarray,
                        resume: bool, hit_pages: Optional[List[int]] = None,
@@ -1307,27 +1327,31 @@ class ServingEngine:
             bucket = next(b for b in self.buckets if b >= len(chunk))
             padded = np.zeros((1, bucket), np.int32)
             padded[0, :len(chunk)] = chunk
-            pt_row = None
-            if self.pager is not None:
-                pt_row = self._pt_rows(slot, cs.start + bucket)
-            self._tok, self.caches, self._pos = \
-                _chunk_prefill_kernel(
-                    self.cfg, self._shd, bool(self._sampled_host[slot]),
-                    self.params, jnp.asarray(padded),
-                    jnp.asarray(cs.start, jnp.int32),
-                    jnp.asarray(len(chunk), jnp.int32),
-                    self.caches, jnp.asarray(slot, jnp.int32), pt_row,
-                    self._tok, self._pos, self._keys, self._temps,
-                    self._topk, self._topp)
-            # resumed streams keep their original prefill_start/first_token
-            t0 = self.vtime
-            self._account_prefill_tokens(
-                len(chunk), not cs.billed and cs.resume_tok is None, cs.req)
-            cs.billed = True
-            if self.tracer is not None:
-                self.tracer.span("prefill_chunk", cs.req.rid, t0, self.vtime,
-                                 self.name, chunk_start=cs.start,
-                                 tokens=len(chunk))
+            with host_span("engine.chunk", rid=cs.req.rid, start=cs.start,
+                           tokens=len(chunk)):
+                pt_row = None
+                if self.pager is not None:
+                    pt_row = self._pt_rows(slot, cs.start + bucket)
+                self._tok, self.caches, self._pos = \
+                    _chunk_prefill_kernel(
+                        self.cfg, self._shd, bool(self._sampled_host[slot]),
+                        self.params, jnp.asarray(padded),
+                        jnp.asarray(cs.start, jnp.int32),
+                        jnp.asarray(len(chunk), jnp.int32),
+                        self.caches, jnp.asarray(slot, jnp.int32), pt_row,
+                        self._tok, self._pos, self._keys, self._temps,
+                        self._topk, self._topp)
+                # resumed streams keep their original prefill_start /
+                # first_token
+                t0 = self.vtime
+                self._account_prefill_tokens(
+                    len(chunk), not cs.billed and cs.resume_tok is None,
+                    cs.req)
+                cs.billed = True
+                if self.tracer is not None:
+                    self.tracer.span("prefill_chunk", cs.req.rid, t0,
+                                     self.vtime, self.name,
+                                     chunk_start=cs.start, tokens=len(chunk))
             cs.start += len(chunk)
             self._register_prefix(cs.req, slot, cs.start)
             progressed = True
@@ -1680,7 +1704,9 @@ class ServingEngine:
         the streaming API inherits the no-per-token-host-sync invariant.
         """
         if self.pager is not None and self.active:
-            k = self._grow_for_block(k)
+            with host_span("engine.grow_pages") as span:
+                k = self._grow_for_block(k)
+                span.set_metadata(k=k, pages_used=self.pager.pages_used)
         snapshot = list(self.active.items())
         batch = len(snapshot)
         if batch == 0:
@@ -1712,23 +1738,25 @@ class ServingEngine:
             room = max(ctx - max_pos, 1)
             kb = next((b for b in self._k_blocks if b <= min(left, room)), 1)
             t0 = time.perf_counter() if wall else 0.0
-            if self.pager is not None:
-                n_ctx = min(ctx // self.ecfg.page_size, self._max_pages)
-                pt = self.pager.table_device()[:, :n_ctx]
-                (self._tok, self.caches, self._pos, tk) = \
-                    _paged_decode_block_kernel(
-                        self.cfg, self._shd, kb, sampled,
-                        self.params, self._tok, self.caches, pt, self._pos,
-                        self._active, self._keys, self._temps, self._topk,
-                        self._topp)
-            else:
-                (self._tok, self.caches, self._pos, tk) = \
-                    _decode_block_kernel(
-                        self.cfg, self._shd, ctx, kb, self.ecfg.max_len,
-                        sampled,
-                        self.params, self._tok, self.caches, self._pos,
-                        self._active, self._keys, self._temps, self._topk,
-                        self._topp)
+            with host_span("engine.dispatch", ctx=ctx, kb=kb, rows=batch,
+                           sampled=sampled):
+                if self.pager is not None:
+                    n_ctx = min(ctx // self.ecfg.page_size, self._max_pages)
+                    pt = self.pager.table_device()[:, :n_ctx]
+                    (self._tok, self.caches, self._pos, tk) = \
+                        _paged_decode_block_kernel(
+                            self.cfg, self._shd, kb, sampled,
+                            self.params, self._tok, self.caches, pt, self._pos,
+                            self._active, self._keys, self._temps, self._topk,
+                            self._topp)
+                else:
+                    (self._tok, self.caches, self._pos, tk) = \
+                        _decode_block_kernel(
+                            self.cfg, self._shd, ctx, kb, self.ecfg.max_len,
+                            sampled,
+                            self.params, self._tok, self.caches, self._pos,
+                            self._active, self._keys, self._temps, self._topk,
+                            self._topp)
             toks_dev.append(tk)        # (kb, B) device, drained at block end
             if wall:
                 # wall-clock mode syncs per chunk (still amortized over kb
@@ -1745,7 +1773,19 @@ class ServingEngine:
             left -= kb
         # single drain per block: (k, B) int32
         self._host_drains += 1
-        toks = np.concatenate(jax.device_get(toks_dev), axis=0)
+        with host_span("engine.drain", steps=k):
+            toks = np.concatenate(jax.device_get(toks_dev), axis=0)
+        with host_span("engine.bookkeep", steps=k, rows=batch) as span:
+            span.set_metadata(
+                finished=self._bookkeep_block(k, snapshot, toks, durs))
+        return k
+
+    def _bookkeep_block(self, k: int, snapshot, toks: np.ndarray,
+                        durs: List[Optional[float]]) -> int:
+        """Host side of a drained block: per-step accounting, the tokens
+        into each stream, finishes, stream events, retirement, pool
+        occupancy and publication.  Returns the streams it finished."""
+        batch = len(snapshot)
         t_block = self.vtime
         done: List[int] = []
         block_toks: Dict[int, List[int]] = {slot: [] for slot, _ in snapshot}
@@ -1805,7 +1845,7 @@ class ServingEngine:
                              self.name, steps=k, batch=batch,
                              freq_mhz=self.controller.freq)
         self._publish_metrics()
-        return k
+        return len(done)
 
     def _step_legacy(self) -> int:
         """Pre-slot data plane: host argmax + batch-wide max(pos).  Kept only
@@ -1882,27 +1922,29 @@ class ServingEngine:
         This is the ``Backend.step`` entry point: the ``serving.api``
         driver loop calls it with no argument; pass ``k=1`` for
         single-step-granularity tests."""
-        self._evict_lapsed()     # opt-in: lapsed decoders free slots first
-        self._admit()
-        progressed = False
-        if self.ecfg.slot_native:
-            progressed = self._advance_chunks()
-        if not self.active:
-            if progressed or self._advance_idle():
+        with host_span("engine.step", pending=len(self.pending),
+                       active=len(self.active)):
+            self._evict_lapsed()     # opt-in: lapsed decoders free slots first
+            self._admit()
+            progressed = False
+            if self.ecfg.slot_native:
+                progressed = self._advance_chunks()
+            if not self.active:
+                if progressed or self._advance_idle():
+                    return 0
+                if self.prefilling or self.pending:
+                    raise RuntimeError(
+                        "serving stalled: pending/prefilling streams cannot "
+                        "obtain pages or slots and nothing is decoding")
                 return 0
-            if self.prefilling or self.pending:
-                raise RuntimeError(
-                    "serving stalled: pending/prefilling streams cannot "
-                    "obtain pages or slots and nothing is decoding")
-            return 0
-        if not self.ecfg.slot_native:
-            self._step_legacy()
-            return 1
-        # clamp to the horizon: _decode_block's batch composition is fixed
-        # for the whole block, so k may never cross a guaranteed leave event
-        horizon = self._horizon()
-        return self._decode_block(max(min(k, horizon) if k is not None
-                                      else horizon, 1))
+            if not self.ecfg.slot_native:
+                self._step_legacy()
+                return 1
+            # clamp to the horizon: _decode_block's batch composition is fixed
+            # for the whole block, so k may never cross a guaranteed leave event
+            horizon = self._horizon()
+            return self._decode_block(max(min(k, horizon) if k is not None
+                                          else horizon, 1))
 
     def _horizon(self) -> int:
         """Steps until the next guaranteed stream leave (no joins possible:

@@ -360,6 +360,67 @@ def test_engine_zero_overhead_regression(model):
     assert e0._m is None and e0.metrics is None and e0.tracer is None
 
 
+@pytest.fixture(scope="module")
+def smoke_programs():
+    """Compiled HLO text of the paged decode block (sampled) and of the
+    slot prefill, for the qwen2-1.5b ``.smoke()`` config."""
+    from repro.configs import get_config
+    from repro.models import NOSHARD, init_cache
+    from repro.serving import engine as E
+    cfg = get_config("qwen2-1.5b").smoke()
+    B, max_len, pages, ps = 4, 128, 33, 16
+    S = jax.ShapeDtypeStruct
+    params = jax.eval_shape(lambda: init_params(KEY, cfg))
+    caches = jax.eval_shape(
+        lambda: init_cache(cfg, B, max_len, paged_pool=(pages, ps)))
+    rows, one = S((B,), np.int32), S((), np.int32)
+    lanes = (S((B, 2), np.uint32), S((B,), np.float32), rows,
+             S((B,), np.float32))
+    decode = E._paged_decode_block_kernel.lower(
+        cfg, NOSHARD, 4, True, params, rows, caches,
+        S((B, max_len // ps), np.int32), rows, S((B,), np.bool_), *lanes)
+    prefill = E._prefill_kernel.lower(
+        cfg, NOSHARD, True, params, S((1, 32), np.int32), one, caches, one,
+        S((1, 32 // ps), np.int32), rows, rows, *lanes)
+    return {k: v.compile().as_text() for k, v in
+            (("decode", decode), ("prefill", prefill))}
+
+
+def _op_names(text):
+    import re
+    return re.findall(r'op_name="([^"]*)"', text)
+
+
+@pytest.mark.parametrize("program,scopes", [
+    ("decode", ("decode_block/", "kv_cache", "attention", "sampler")),
+    ("prefill", ("prefill/", "attention", "sampler"))])
+def test_named_scopes_reach_the_hlo_op_names(smoke_programs, program,
+                                             scopes):
+    """The device side of the trace: each program's ops carry the engine's
+    ``jax.named_scope`` path in their ``op_name`` metadata, under the
+    program's own scope."""
+    names = _op_names(smoke_programs[program])
+    outer = scopes[0]
+    for scope in scopes:
+        assert any(scope in n for n in names), scope
+    # the sampler and attention sit inside the program's scope (reducer
+    # bodies carry a path relative to their reduce, without the program)
+    inner = [n for n in names if n.startswith("jit(")
+             and ("/sampler/" in n or "/attention/" in n)]
+    assert inner and all(outer in n for n in inner)
+    if program == "decode":
+        kv = [n for n in names if "/kv_cache/" in n]
+        assert kv and not any("/attention/" in n for n in kv)
+
+
+def test_host_span_records_nothing_without_a_profile():
+    from repro.core.tracing import host_span
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    with host_span("engine.step", pending=1, active=2) as span:
+        span.set_metadata(finished=0)
+    assert isinstance(span, jax.profiler.TraceAnnotation)
+
+
 def test_engine_evict(model):
     cfg, params = model
     eng = _engine(cfg, params)
