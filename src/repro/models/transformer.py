@@ -25,6 +25,7 @@ from .attention import attention
 from .moe import init_moe, apply_moe
 from .ssm import init_ssm, ssm_forward, ssm_decode_step
 from .rglru import init_rglru, rglru_forward, rglru_decode_step
+from repro.kernels.paged_decode_attention import paged_decode_attention
 
 
 # -- sharding context ------------------------------------------------------------
@@ -243,8 +244,53 @@ def param_specs(cfg: ModelConfig, shd: ShardCtx) -> Dict:
 
 # -- block application ----------------------------------------------------------------
 
+def paged_decode_kernel_ok(cfg: ModelConfig, shd: ShardCtx) -> bool:
+    """Whether a paged decode step may attend through the Pallas kernel
+    (``kernels/paged_decode_attention``) where the program is lowered for a
+    TPU.  The kernel reads a single device's bf16/f32 pool whose KV heads
+    are lane-aligned; int8 pools (dequantisation) and serving meshes (GSPMD
+    placement of the pool's pages) keep the gather path.  The platform half
+    of the choice is made inside the program (``jax.lax.platform_dependent``
+    in ``_apply_attn``), so a program lowered for the CPU gathers."""
+    return shd.mesh is None and not cfg.kv_quant and cfg.head_dim % 128 == 0
+
+
+def _paged_gather_attention(cfg: ModelConfig, shd: ShardCtx, window: int, q,
+                            cache, page_table, pos_v, active):
+    """Paged decode attention in XLA: gather the ctx-bucketed page chains as
+    a dense (B, n_pages*ps) context and attend with position masks."""
+    del active            # inactive rows attend too; the caller drops them
+    B = q.shape[0]
+    with jax.named_scope("kv_cache"):
+        k_att, v_att = KV.paged_cache_kv_arrays(cache, page_table, q.dtype)
+        # serving mesh: the pool's page axis is data-sharded while the
+        # gathered per-row context is batch-sharded — constrain the
+        # gather output so GSPMD routes pages once instead of
+        # replicating the pool into every shard's gather (guidance only;
+        # rows are independent, so placement cannot change the bits)
+        k_att = shd.cs(k_att, "b", None, None, None)
+        v_att = shd.cs(v_att, "b", None, None, None)
+    k_pos = jnp.broadcast_to(
+        KV.paged_key_positions(k_att.shape[1], pos_v + 1),
+        (B, k_att.shape[1]))
+    return attention(q, k_att, v_att, pos_v[:, None], k_pos, window=window,
+                     softcap=cfg.attn_softcap, scale=cfg.attn_scale,
+                     unroll=cfg.unroll_scans)
+
+
+def _paged_kernel_attention(cfg: ModelConfig, window: int, q, cache,
+                            page_table, pos_v, active):
+    """Paged decode attention through the Pallas kernel: each row's live
+    pages are read from the pool in place, no gather and no GQA repeat."""
+    with jax.named_scope("attention"):
+        out = paged_decode_attention(
+            q[:, 0], cache["kp"], cache["vp"], page_table, pos_v, active,
+            window=window, softcap=cfg.attn_softcap, scale=cfg.attn_scale)
+    return out[:, None]
+
+
 def _apply_attn(cfg: ModelConfig, p, x, kind, *, mode, positions, cache, pos,
-                shd, slot=None, length=None, page_table=None):
+                shd, slot=None, length=None, page_table=None, active=None):
     B, S, _ = x.shape
     q = x @ p["attn"]["q"]
     k = x @ p["attn"]["k"]
@@ -267,28 +313,21 @@ def _apply_attn(cfg: ModelConfig, p, x, kind, *, mode, positions, cache, pos,
     window = cfg.window if kind == LOCAL_ATTN else 0
     new_cache = None
     if mode == "decode" and KV.is_paged(cache):
-        # paged pool: scatter the new token by page table, gather the
-        # ctx-bucketed page chain back as a dense (B, n_pages*ps) context
+        # paged pool: scatter the new token by page table, then attend to
+        # each row's chain (Pallas kernel on TPU, dense gather elsewhere)
         pos_v = jnp.broadcast_to(jnp.atleast_1d(jnp.asarray(pos, jnp.int32)),
                                  (B,))
         with jax.named_scope("kv_cache"):
             new_cache = KV.paged_cache_write_decode(cache, k, v, pos_v,
                                                     page_table)
-            k_att, v_att = KV.paged_cache_kv_arrays(new_cache, page_table,
-                                                    q.dtype)
-            # serving mesh: the pool's page axis is data-sharded while the
-            # gathered per-row context is batch-sharded — constrain the
-            # gather output so GSPMD routes pages once instead of
-            # replicating the pool into every shard's gather (guidance only;
-            # rows are independent, so placement cannot change the bits)
-            k_att = shd.cs(k_att, "b", None, None, None)
-            v_att = shd.cs(v_att, "b", None, None, None)
-        k_pos = jnp.broadcast_to(
-            KV.paged_key_positions(k_att.shape[1], pos_v + 1),
-            (B, k_att.shape[1]))
-        out = attention(q, k_att, v_att, positions, k_pos, window=window,
-                        softcap=cfg.attn_softcap, scale=cfg.attn_scale,
-                        unroll=cfg.unroll_scans)
+        args = (q, new_cache, page_table, pos_v, active)
+        gather = functools.partial(_paged_gather_attention, cfg, shd, window)
+        if paged_decode_kernel_ok(cfg, shd):
+            out = jax.lax.platform_dependent(
+                *args, default=gather,
+                tpu=functools.partial(_paged_kernel_attention, cfg, window))
+        else:
+            out = gather(*args)
     elif mode == "decode":
         with jax.named_scope("kv_cache"):
             new_cache = KV.cache_write_decode(cache, k, v, pos)
@@ -394,7 +433,8 @@ def _apply_block(cfg: ModelConfig, kind: str, p, x, *, mode, positions,
         mix, new_cache = _apply_attn(cfg, p, h, kind, mode=mode,
                                      positions=positions, cache=cache,
                                      pos=pos, shd=shd, slot=slot,
-                                     length=length, page_table=page_table)
+                                     length=length, page_table=page_table,
+                                     active=active)
     elif kind == SSM:
         if mode == "decode":
             mix, new_cache = ssm_decode_step(cfg, p["ssm"], h, cache)
@@ -455,7 +495,7 @@ def _run_stages(cfg: ModelConfig, params, x, *, mode, positions, caches, pos,
     for si, ((kinds, n_rep), sp) in enumerate(zip(stages_of(cfg), params["stages"])):
         stage_cache = caches[si] if caches is not None else None
 
-        def group_fn(x, group_p, group_c):
+        def group_fn(x, group_p, group_c, pt=page_table):
             auxs = jnp.zeros((), jnp.float32)
             outs = []
             for j, kind in enumerate(kinds):
@@ -464,7 +504,7 @@ def _run_stages(cfg: ModelConfig, params, x, *, mode, positions, caches, pos,
                                         mode=mode, positions=positions,
                                         cache=c, pos=pos, shd=shd, slot=slot,
                                         length=length, valid=valid,
-                                        page_table=page_table, active=active)
+                                        page_table=pt, active=active)
                 auxs = auxs + a
                 outs.append(nc)
             return x, tuple(outs), auxs
@@ -472,7 +512,40 @@ def _run_stages(cfg: ModelConfig, params, x, *, mode, positions, caches, pos,
         if remat:
             group_fn = jax.checkpoint(group_fn)
 
-        if stage_cache is not None:
+        paged = tuple(KV.is_paged(c) for c in stage_cache) \
+            if stage_cache is not None else ()
+        if mode == "decode" and any(paged) and paged_decode_kernel_ok(cfg,
+                                                                      shd):
+            # the paged pools ride in the scan carry, flattened to (n_rep*P,
+            # ps, ...): layer i addresses its pool as pages i*P.. through an
+            # offset page table, so the token write lands in place and the
+            # attention reads the pool where it lies — no per-layer slice of
+            # the pool is copied out and back
+            P = next(c["kp"].shape[1] for c, g in zip(stage_cache, paged) if g)
+            pools = tuple(
+                jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), c)
+                if g else None for c, g in zip(stage_cache, paged))
+            rest = tuple(None if g else c for c, g in zip(stage_cache, paged))
+
+            def body(carry, xs):
+                x, aux, pools = carry
+                gp, gc, i = xs
+                gc = tuple(pool if g else c
+                           for pool, c, g in zip(pools, gc, paged))
+                x, ncache, a = group_fn(x, gp, gc, page_table + i * P)
+                pools = tuple(n if g else None for n, g in zip(ncache, paged))
+                ncache = tuple(None if g else n for n, g in zip(ncache, paged))
+                return (x, aux + a, pools), ncache
+
+            (x, aux_total, pools), ys = jax.lax.scan(
+                body, (x, aux_total, pools),
+                (sp, rest, jnp.arange(n_rep, dtype=jnp.int32)),
+                unroll=cfg.unroll_scans)
+            new_caches.append(tuple(
+                jax.tree.map(lambda a, o: a.reshape(o.shape), pool, c)
+                if g else y for pool, y, c, g in zip(pools, ys, stage_cache,
+                                                     paged)))
+        elif stage_cache is not None:
             def body(carry, xs):
                 x, aux = carry
                 gp, gc = xs
@@ -786,13 +859,15 @@ def decode_step(params, cfg: ModelConfig, tokens, caches, pos,
 
     ``page_table`` ((B, n_pages) int32) must be passed when ``caches`` hold
     paged pools (``init_cache(..., paged_pool=...)``): each row's K/V write
-    and gather go through its page chain, ctx-bounded by the caller-sliced
-    table width.
+    and attention go through its page chain, ctx-bounded by the
+    caller-sliced table width.
 
     ``active`` ((B,) bool) freezes inactive rows' *recurrent* (SSM/RG-LRU)
-    states: K/V writes of inactive rows are hidden by position masking, but
-    recurrent state has no positions, so without the mask a mid-chunked-
-    prefill row would be polluted by other streams' decode blocks.
+    states, and lets the paged attention kernel skip inactive rows' chains
+    (their output is discarded): K/V writes of inactive rows are hidden by
+    position masking, but recurrent state has no positions, so without the
+    mask a mid-chunked-prefill row would be polluted by other streams'
+    decode blocks.
     """
     B = tokens.shape[0]
     if shd.mesh is not None:

@@ -84,6 +84,7 @@ from repro.launch.shardings import (gather_replicated, make_serving_shard_ctx,
 from repro.models import (ModelConfig, NOSHARD, init_cache, init_params,
                           prefill, prefill_into_slot, prefill_chunk_into_slot,
                           decode_step, sample_tokens_batched)
+from repro.models.transformer import paged_decode_kernel_ok
 from repro.models.config import FULL_ATTN, LOCAL_ATTN
 from repro.models.kvcache import (attn_buffer_len, is_paged,
                                   paged_chain_extract, paged_chain_insert,
@@ -594,6 +595,13 @@ class ServingEngine:
         else:
             self.pager = None
             pool = None
+        # how paged decode blocks attend: the Pallas kernel where the
+        # program is lowered for a TPU, else the dense gather (the choice
+        # itself is made inside the traced program; this only reports it)
+        devices = next(iter(jax.tree.leaves(self.params))).devices()
+        self._attn = "kernel" if (
+            ecfg.paged and paged_decode_kernel_ok(cfg, self._shd)
+            and {d.platform for d in devices} == {"tpu"}) else "gather"
         self.caches = init_cache(cfg, B, ecfg.max_len,
                                  dtype=jnp.dtype(ecfg.cache_dtype),
                                  paged_pool=pool)
@@ -642,6 +650,8 @@ class ServingEngine:
         # sinks-on/sinks-off runs), plus optional metric/trace sinks
         self.name = name
         self._host_drains = 0
+        self._decode_blocks = 0         # decode block programs dispatched
+        self._kernel_blocks = 0         # ... of them through the paged kernel
         self.metrics = None
         self.tracer = None
         self.ledger = None          # core.attribution.EnergyLedger (opt-in)
@@ -1740,6 +1750,8 @@ class ServingEngine:
             t0 = time.perf_counter() if wall else 0.0
             with host_span("engine.dispatch", ctx=ctx, kb=kb, rows=batch,
                            sampled=sampled):
+                self._decode_blocks += 1
+                self._kernel_blocks += self._attn == "kernel"
                 if self.pager is not None:
                     n_ctx = min(ctx // self.ecfg.page_size, self._max_pages)
                     pt = self.pager.table_device()[:, :n_ctx]
@@ -2026,6 +2038,8 @@ class ServingEngine:
             "shed": self._shed,
             "pending": len(self.pending),
             "active": len(self.active),
+            "decode_blocks": self._decode_blocks,
+            "decode_blocks_kernel": self._kernel_blocks,
             "prefilling": len(self.prefilling),
             "vtime_s": self.vtime,
             # active + idle, matching the cluster's legacy dict (idle is 0

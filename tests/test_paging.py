@@ -309,33 +309,151 @@ def test_allocator_rejects_overlong_chain():
 
 # -- Pallas paged decode kernel ------------------------------------------------
 
-@pytest.mark.parametrize("case", [
-    # B, Hq, KH, P, ps, n_pages, hd, window
-    (2, 8, 2, 16, 16, 8, 64, 0),
-    (3, 4, 4, 12, 8, 6, 128, 0),
-    (1, 16, 4, 16, 16, 4, 64, 24),     # GQA + sliding window
-])
-def test_paged_decode_kernel_matches_oracle(case):
+KERNEL_CASES = {
+    # qwen2-1.5b heads (12 query, 2 kv, hd 128, page 16); the table spans a
+    # 512-position bucket, walked in blocks of 4 pages
+    "qwen2-bucket": dict(Hq=12, KH=2, hd=128, n=32, pos=[511, 100, 7],
+                         block_pages=4),
+    # qwen3-14b heads: G = 40 / 8 = 5, padded to the sublane tile
+    "qwen3-g5": dict(Hq=40, KH=8, hd=128, n=8, pos=[90, 33]),
+    # gemma2-like: hd 256, logit softcap and a sliding window
+    "hd256-softcap-window": dict(Hq=16, KH=8, hd=256, n=8, pos=[100, 20, 39],
+                                 window=40, softcap=50.0, block_pages=2),
+    "position-0": dict(Hq=12, KH=2, hd=128, n=4, pos=[0, 0]),
+    "page-last-slot": dict(Hq=12, KH=2, hd=128, n=4, pos=[15, 31, 63],
+                           block_pages=1),
+    # chains shorter than the table: the tail points at the scratch page
+    "scratch-tail": dict(Hq=4, KH=4, hd=128, n=8, pos=[5, 40, 17],
+                         dtype="float32"),
+    # row 1 is inactive with a stale position past its (scratch) table
+    "inactive-row": dict(Hq=12, KH=2, hd=128, n=6, pos=[50, 5000, 30],
+                         inactive=[1], block_pages=2),
+}
+
+
+def _paged_chains(rng, pos, n, ps, num_pages, inactive=()):
+    """Distinct physical pages for each row's chain up to its position; the
+    rest of the table (and an inactive row's whole table) is scratch."""
+    pt = np.full((len(pos), n), SCRATCH_PAGE, np.int32)
+    free = rng.permutation(np.arange(1, num_pages))
+    used = 0
+    for b, p in enumerate(pos):
+        if b in inactive:
+            continue
+        c = min(p // ps + 1, n)
+        pt[b, :c] = free[used:used + c]
+        used += c
+    return pt
+
+
+@pytest.mark.parametrize("name", list(KERNEL_CASES))
+def test_paged_decode_kernel_matches_oracle(name):
     from repro.kernels import paged_decode_attention, paged_decode_attention_ref
-    B, Hq, KH, P, ps, n, hd, win = case
+    c = dict(dict(ps=16, P=48, window=0, softcap=0.0, inactive=(),
+                  block_pages=64, dtype="bfloat16"), **KERNEL_CASES[name])
+    B, ps, hd = len(c["pos"]), c["ps"], c["hd"]
     ks = jax.random.split(KEY, 3)
-    q = jax.random.normal(ks[0], (B, Hq, hd), jnp.float32)
-    kp = jax.random.normal(ks[1], (P, ps, KH, hd), jnp.float32)
-    vp = jax.random.normal(ks[2], (P, ps, KH, hd), jnp.float32)
-    rng = np.random.default_rng(0)
-    pt = np.zeros((B, n), np.int32)
-    qpos = np.zeros((B,), np.int32)
-    for b in range(B):
-        cov = int(rng.integers(1, n + 1))        # partial chains: tail pages
-        pt[b, :cov] = rng.choice(np.arange(1, P), size=cov, replace=False)
-        qpos[b] = rng.integers(0, cov * ps)      # point at scratch, masked
-    out = paged_decode_attention(q, kp, vp, jnp.asarray(pt),
-                                 jnp.asarray(qpos), window=win,
-                                 interpret=True)
-    want = paged_decode_attention_ref(q, kp, vp, jnp.asarray(pt),
-                                      jnp.asarray(qpos), window=win)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+    q = jax.random.normal(ks[0], (B, c["Hq"], hd), jnp.float32)
+    kp, vp = (jax.random.normal(k, (c["P"], ps, c["KH"], hd),
+                                jnp.float32).astype(c["dtype"])
+              for k in ks[1:])
+    pt = jnp.asarray(_paged_chains(np.random.default_rng(0), c["pos"],
+                                   c["n"], ps, c["P"], c["inactive"]))
+    pos = jnp.asarray(c["pos"], jnp.int32)
+    active = jnp.asarray([b not in c["inactive"] for b in range(B)])
+    kw = dict(window=c["window"], softcap=c["softcap"])
+    out = paged_decode_attention(q, kp, vp, pt, pos, active,
+                                 block_pages=c["block_pages"],
+                                 interpret=True, **kw)
+    want = paged_decode_attention_ref(q, kp, vp, pt, pos, **kw)
+    live = np.asarray(active)
+    np.testing.assert_allclose(np.asarray(out)[live], np.asarray(want)[live],
                                atol=2e-5, rtol=2e-5)
+    assert np.isfinite(np.asarray(out)).all()
+
+
+def _lane_aligned_cfg():
+    """qwen2-1.5b's smoke config with 128-wide heads: statically eligible
+    for the paged decode kernel."""
+    from repro.configs import get_config
+    return get_config("qwen2-1.5b").smoke().replace(head_dim=128,
+                                                    dtype="float32")
+
+
+def test_apply_attn_paged_decode_kernel_matches_gather(monkeypatch):
+    """``_apply_attn``'s paged decode branch through the kernel (interpret
+    mode, selected as on a TPU) equals the gather path it replaces on the
+    CPU: same token write, same attention output for every active row."""
+    import functools
+    from repro.kernels import paged_decode_attention
+    from repro.models import transformer as T
+    from repro.models.config import FULL_ATTN
+    cfg = _lane_aligned_cfg()
+    params = init_params(KEY, cfg)
+    p = jax.tree.map(lambda a: a[0], params["stages"][0]["blocks"][0])
+    B, P, ps, n = 3, 16, 16, 4
+    pool = init_cache(cfg, B, n * ps, paged_pool=(P, ps),
+                      dtype=jnp.float32)[0][0]
+    ks = jax.random.split(KEY, 3)
+    cache = {k: jax.random.normal(kk, a.shape[1:], a.dtype)
+             for kk, (k, a) in zip(ks, sorted(pool.items()))}
+    pos = jnp.asarray([40, 9, 63], jnp.int32)
+    pt = jnp.asarray(_paged_chains(np.random.default_rng(1), [40, 9, 63], n,
+                                   ps, P))
+    active = jnp.asarray([True, False, True])
+    x = jax.random.normal(ks[2], (B, 1, cfg.d_model), jnp.float32)
+
+    def run():
+        return T._apply_attn(cfg, p, x, FULL_ATTN, mode="decode",
+                             positions=pos[:, None], cache=cache, pos=pos,
+                             shd=T.NOSHARD, page_table=pt, active=active)
+
+    want, want_cache = run()                 # lowered for the CPU: gather
+    monkeypatch.setattr(T, "paged_decode_attention", functools.partial(
+        paged_decode_attention, interpret=True))
+    monkeypatch.setattr(jax.lax, "platform_dependent",
+                        lambda *a, tpu, default: tpu(*a))
+    got, got_cache = run()
+    live = np.asarray(active)
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
+                               atol=2e-5, rtol=2e-5)
+    for k in cache:
+        np.testing.assert_array_equal(got_cache[k], want_cache[k])
+
+
+def test_paged_decode_kernel_predicate():
+    """The kernel serves single-device, non-int8 pools with lane-aligned
+    heads; int8 pools and serving meshes keep the gather path."""
+    from jax.sharding import Mesh
+    from repro.launch.shardings import make_serving_shard_ctx
+    from repro.models.transformer import NOSHARD, paged_decode_kernel_ok
+    cfg = _lane_aligned_cfg()
+    mesh = Mesh(np.asarray(jax.devices()[:1]).reshape(1, 1),
+                ("data", "model"))
+    assert paged_decode_kernel_ok(cfg, NOSHARD)
+    assert not paged_decode_kernel_ok(cfg, make_serving_shard_ctx(mesh))
+    assert not paged_decode_kernel_ok(cfg.replace(kv_quant=True), NOSHARD)
+    assert not paged_decode_kernel_ok(cfg.replace(head_dim=64), NOSHARD)
+
+
+@pytest.mark.parametrize("variant", ["lane_aligned", "kv_quant"])
+def test_paged_decode_off_tpu_serves_like_dense(variant):
+    """Off a TPU every paged engine attends through the gather: a
+    kernel-eligible model (pools carried through the layer scan) and an
+    int8 pool serve the burst token-for-token like the dense engine, and
+    the engine's kernel counter reads 0."""
+    cfg = _lane_aligned_cfg() if variant == "lane_aligned" else \
+        _cfg("kv_quant")
+    params = init_params(KEY, cfg)
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(0, cfg.vocab_size, size=n) for n in (19, 7, 12)]
+    outs = [10, 6, 8]
+    eng = _engine(cfg, params, paged=True)
+    assert eng._attn == "gather"
+    t_paged = _serve(eng, prompts, outs)
+    s = eng.stats()
+    assert s["decode_blocks"] > 0 and s["decode_blocks_kernel"] == 0
+    assert t_paged == _serve(_engine(cfg, params, paged=False), prompts, outs)
 
 
 # -- per-phase accounting ------------------------------------------------------

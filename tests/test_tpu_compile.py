@@ -78,11 +78,11 @@ def test_paged_decode_attention_compiles(chip):
     from repro.kernels import paged_decode_attention
     spec, _ = chip
     c = _compile(paged_decode_attention,
-                 spec((BATCH, HQ, HD), jnp.bfloat16),
+                 spec((BATCH, HQ, HD), jnp.float32),
                  spec((N_PAGES, PAGE, KH, HD), jnp.bfloat16),
                  spec((N_PAGES, PAGE, KH, HD), jnp.bfloat16),
                  spec((BATCH, MAX_LEN // PAGE), jnp.int32),
-                 spec((BATCH,), jnp.int32))
+                 spec((BATCH,), jnp.int32), spec((BATCH,), jnp.bool_))
     assert "tpu_custom_call" in c.as_text()
 
 
@@ -176,3 +176,25 @@ def test_prefill_program_fits_one_chip(chip, engine_state):
         spec((BATCH,), jnp.float32)).compile()
     assert c.memory_analysis().alias_size_in_bytes > 0
     assert _device_bytes(c) < HBM_BYTES
+
+
+def test_paged_decode_block_program_attends_through_the_kernel(chip,
+                                                               engine_state):
+    """Lowered for the v5e, the decode block takes the Pallas paged kernel
+    (the platform choice is made inside the program) and no longer makes
+    the dense (B, bucket, KH, hd) K and V gather: its temporaries are
+    smaller than that gather alone."""
+    from repro.serving.engine import _paged_decode_block_kernel
+    spec, _ = chip
+    params, caches = engine_state
+    rows = spec((BATCH,), jnp.int32)
+    c = _paged_decode_block_kernel.lower(
+        CFG, NOSHARD, 16, False, params, rows, caches,
+        spec((BATCH, MAX_LEN // PAGE), jnp.int32), rows,
+        spec((BATCH,), jnp.bool_), spec((BATCH, 2), jnp.uint32),
+        spec((BATCH,), jnp.float32), rows,
+        spec((BATCH,), jnp.float32)).compile()
+    assert "tpu_custom_call" in c.as_text()
+    # the gather path gathered K and V in the query's dtype, float32
+    dense_gather = 2 * BATCH * MAX_LEN * KH * HD * 4
+    assert c.memory_analysis().temp_size_in_bytes < dense_gather
