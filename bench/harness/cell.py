@@ -104,7 +104,8 @@ class Run:
     traffic: dict
     window: object
     setup_s: float
-    peaks: dict
+    peaks: dict                           # of one chip
+    chips: int = 1                        # the cell's
     trace: Optional[dict] = None          # devtrace.reduce output
     traced: Optional[tuple] = None        # host (start, stop) of the trace
     occupancy: list = dataclasses.field(default_factory=list)
@@ -181,13 +182,13 @@ def sample_for_check(window, seed: int, min_tokens: int, max_reqs: int):
               tr.req.lane_seed or 0)) for tr in pick]
 
 
-def logit_gaps(spec, seed, samples, quant=None):
+def logit_gaps(spec, seed, samples, quant=None, placement=None):
     """Per sample, by how much the reference's logits would have to move
     for each served token to be the one its sampling rule produces (or,
     with ``quant``, for the token that precision produces at the same
-    position)."""
+    position).  The weights are remade where ``placement`` puts them."""
     from . import reference, weights
-    params = weights.make_params(spec, seed)
+    params = weights.make_params(spec, seed, placement)
     seqs = [np.concatenate([p, t[:-1]]) for p, t, _ in samples]
     starts = [len(p) - 1 for p, _, _ in samples]
     targets = [t for _, t, _ in samples]
@@ -198,6 +199,36 @@ def logit_gaps(spec, seed, samples, quant=None):
                                            mode="choose")
     return reference.read_positions(spec, params, seqs, starts, targets,
                                     rules)
+
+
+def weight_placement(conf: dict, cfg, spec: dict, chips: int,
+                     peaks: dict):
+    """Where the engine keeps each weight over its mesh, so that the weights
+    are made there, once (None on one chip).  Refuses first, before any
+    weight is made, an engine mesh that is not the cell's chips, and
+    weights that alone overfill a chip."""
+    from . import weights
+    mesh = conf["engine"].get("mesh") or (1, 1)
+    span = int(np.prod(mesh))
+    if span != chips:
+        raise SystemExit(f"{conf['arch']}: engine.mesh "
+                         f"{conf['engine'].get('mesh')} spans {span} chips, "
+                         f"the cell {chips}")
+    placement = None
+    if chips > 1:
+        from repro.launch.mesh import make_serving_mesh
+        from repro.launch.shardings import named, serving_param_specs
+        m = make_serving_mesh(*mesh)
+        placement = named(m, serving_param_specs(cfg, m)[0])
+    if "hbm_bytes" in peaks:
+        total = weights.param_bytes(spec)
+        chip = weights.param_bytes(spec, placement)
+        if chip > peaks["hbm_bytes"]:
+            raise SystemExit(
+                f"{conf['arch']}: {total} bytes of weights over {chips} "
+                f"chips is {chip} a chip, more than its "
+                f"{peaks['hbm_bytes']:.0f}")
+    return placement
 
 
 def serve(found: dict, seed: int, seconds: float, trace: bool, log,
@@ -213,13 +244,17 @@ def serve(found: dict, seed: int, seconds: float, trace: bool, log,
     def stage(what):
         log(f"[setup] {time.perf_counter() - t_start:8.3f} s {what}")
 
-    devs = device_info(require_tpu, cell["chips"])
-    stage(f"devices: {devs}")
+    chips = cell["chips"]
+    devs = device_info(require_tpu, chips)
+    ours = devs[:chips]
+    stage(f"devices: {devs}, the cell's: {[d.id for d in ours]}")
     clog = CompileLog()
     conf, mix = found["config"], found["traffic"]
     cfg = program_config(conf, found["reduced"], strict)
     spec = weights.spec_of(conf)
-    params = weights.make_params(spec, seed)
+    peaks = peaks_for(devs[0], require_tpu)
+    placement = weight_placement(conf, cfg, spec, chips, peaks)
+    params = weights.make_params(spec, seed, placement)
     from repro.models import init_params
     want = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
     got = jax.tree.map(lambda x: (x.shape, x.dtype), params)
@@ -257,10 +292,12 @@ def serve(found: dict, seed: int, seconds: float, trace: bool, log,
     window_compiles = clog.n - m[0]
     log(f"[window] {window.seconds:.6f} s, {len(window.rounds)} rounds, "
         f"{len(window.tracks)} requests, in-window {clog.since(m)}")
-    stats = devs[0].memory_stats() or {}
-    peak = stats.get("peak_bytes_in_use")
-    log(f"[window] peak_bytes_in_use={peak} "
-        f"bytes_limit={stats.get('bytes_limit')}")
+    stats = [d.memory_stats() or {} for d in ours]
+    peak = max((st["peak_bytes_in_use"] for st in stats
+                if "peak_bytes_in_use" in st), default=None)
+    log(f"[window] peak_bytes_in_use="
+        f"{[st.get('peak_bytes_in_use') for st in stats]} "
+        f"bytes_limit={stats[0].get('bytes_limit')}")
     if after_window is not None:
         after_window(server, window)
     for tr in window.tracks:
@@ -271,22 +308,22 @@ def serve(found: dict, seed: int, seconds: float, trace: bool, log,
                  or (plan.loop == "open" and tr.first_token is None))
     vocab_ok = all(0 <= t < spec["vocab"] for tr in window.tracks
                    for t in tr.tokens)
-    run = Run(spec, mix, window,
-              setup_s=window.start - t_start,
-              peaks=peaks_for(devs[0], require_tpu))
+    run = Run(spec, mix, window, setup_s=window.start - t_start,
+              peaks=peaks, chips=chips)
     if tracer is not None:
         from . import devtrace
         run.occupancy = tracer.occupancy
         if tracer.stop is not None:
             run.traced = (tracer.start, tracer.stop)
-            run.trace = devtrace.reduce(devtrace.read(tdir))
+            run.trace = devtrace.reduce(devtrace.read(tdir),
+                                        [d.id for d in ours])
         shutil.rmtree(tdir, ignore_errors=True)
     samples = sample_for_check(window, seed,
                                found["limits"]["check_tokens"],
                                found["limits"]["check_requests"])
     info = dict(peak=peak, failed=failed, vocab_ok=vocab_ok,
                 attempted=len(mine), window_compiles=window_compiles,
-                device=devs[0], n_devices=len(devs))
+                device=devs[0], n_devices=len(devs), placement=placement)
     del server, eng, tracer
     gc.collect()
     return run, samples, info
@@ -308,8 +345,8 @@ def check(run: Run, samples, info, seed: int, limits: dict,
     the control: the reference in that precision in the program's place."""
     gap = None
     if samples:
-        gap = float(max(np.max(g) for g in logit_gaps(run.spec, seed,
-                                                      samples, quant)))
+        gap = float(max(np.max(g) for g in logit_gaps(
+            run.spec, seed, samples, quant, info.get("placement"))))
     served = sum(len(t) for _, t, _ in samples)
     return {
         "logit_gap": (gap, limits["logit_gap"]["limit"]),

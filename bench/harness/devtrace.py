@@ -5,6 +5,8 @@ device time per jitted program, and the operations that took most time.
 The window is the host span ``bench.window`` that the harness opens when
 tracing starts and closes before it stops; device events are clipped to it.
 Each idle gap is named by the shortest host event that covers its middle.
+Only the planes of the cell's own chips are read, so the chips of a host
+that the cell leaves unused do not count as idle ones.
 
     python bench/harness/devtrace.py <trace dir or .xplane.pb>   # dump
 """
@@ -15,7 +17,7 @@ import os
 import re
 import sys
 from collections import defaultdict
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, Iterable, List, Optional, Tuple
 
 WINDOW_SPAN = "bench.window"
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
@@ -101,17 +103,27 @@ def read(path: str) -> dict:
     return {"device": dev, "host": host}
 
 
-def reduce(tr: dict) -> Optional[dict]:
-    """Busy seconds (mean over devices), window seconds, device seconds per
-    program, top device ops and idle gaps; None without device events or
-    without the window span."""
+def planes(tr: dict, devices: Optional[Iterable[int]] = None) -> dict:
+    """The device planes of ``devices`` (``/device:TPU:<id>``), or all."""
+    if devices is None:
+        return tr["device"]
+    keep = set(devices)
+    return {i: p for i, p in tr["device"].items() if i in keep}
+
+
+def reduce(tr: dict, devices: Optional[Iterable[int]] = None
+           ) -> Optional[dict]:
+    """Busy seconds (mean over the planes of ``devices``, or all), window
+    seconds, device seconds per program, top device ops and idle gaps;
+    None without device events or without the window span."""
     spans = [e for e in tr["host"] if e[2] == WINDOW_SPAN]
-    if not spans or not tr["device"]:
+    dev = planes(tr, devices)
+    if not spans or not dev:
         return None
     w0, w1 = spans[0][0], spans[0][1]
     busy, modules, ops = [], defaultdict(float), defaultdict(float)
     gaps = []
-    for lines in tr["device"].values():
+    for lines in dev.values():
         iv = []
         for a, b, name, st in lines.get(OPS_LINE, []):
             a, b = max(a, w0), min(b, w1)
@@ -119,7 +131,7 @@ def reduce(tr: dict) -> Optional[dict]:
                 iv.append((a, b))
                 label = op_label(name)
                 if label is not None:
-                    ops[label] += (b - a) / len(tr["device"])
+                    ops[label] += (b - a) / len(dev)
         u = _union(iv)
         busy.append(sum(b - a for a, b in u))
         edges = [w0] + [x for ab in u for x in ab] + [w1]
@@ -128,7 +140,7 @@ def reduce(tr: dict) -> Optional[dict]:
         for a, b, name, _ in lines.get(MODULES_LINE, []):
             a, b = max(a, w0), min(b, w1)
             if b > a:
-                modules[module_name(name)] += (b - a) / 1e9 / len(tr["device"])
+                modules[module_name(name)] += (b - a) / 1e9 / len(dev)
     if not any(busy):
         return None
     gaps.sort(key=lambda g: g[0] - g[1])

@@ -9,7 +9,10 @@ MLP, final RMSNorm, tied or untied output head.
 
 It runs layer by layer over whole sequences, and the head only at the
 positions asked for, so a 14B-wide model fits one chip in float32 (the
-bfloat16 weights are widened one layer at a time).
+bfloat16 weights are widened one layer at a time).  It computes on one
+chip, the first that holds the weights: where they are split over several,
+each layer, and the embedding table for the head, is gathered to it in
+turn, so the numbers do not depend on how the weights are placed.
 
 At each position it also applies the request's sampling rule to its own
 logits, as ``SamplingParams`` states it: greedy is the argmax; a sampled
@@ -209,6 +212,33 @@ def _skey(s: dict) -> tuple:
     return tuple(sorted(s.items()))
 
 
+@functools.lru_cache(maxsize=None)
+def _gather_fn(mesh):
+    """A reshard to replicated over ``mesh``: an all-gather on the chips."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec
+    return jax.jit(lambda t: t,
+                   out_shardings=NamedSharding(mesh, PartitionSpec()))
+
+
+def _home(params):
+    """A function that brings a tree of arrays to the one device the
+    reference computes on, the first that holds the weights; arrays split
+    over several chips are gathered on the chips and the first one's
+    copy is kept."""
+    import jax
+    emb = params["embed"]["embedding"]
+    dev = min(emb.devices(), key=lambda d: d.id)
+    if len(emb.devices()) == 1:
+        return lambda tree: jax.device_put(tree, dev)
+    gather = _gather_fn(emb.sharding.mesh)
+
+    def one(a):
+        return next(sh.data for sh in gather(a).addressable_shards
+                    if sh.device == dev)
+    return lambda tree: jax.tree.map(one, tree)
+
+
 def hidden(s: dict, params, seqs: Sequence[np.ndarray], quant=None):
     """Final hidden states (before the last norm), (len(seqs), T, d) f32,
     T the padded length; pads sit after each sequence and, attention
@@ -220,11 +250,12 @@ def hidden(s: dict, params, seqs: Sequence[np.ndarray], quant=None):
     for i, q in enumerate(seqs):
         toks[i, :len(q)] = q
     blk = params["stages"][0]["blocks"][0]
+    home = _home(params)
     table = params["embed"]["embedding"]
-    x = jnp.take(table, jnp.asarray(toks), axis=0).astype(jnp.float32)
+    x = home(jnp.take(table, jnp.asarray(toks), axis=0)).astype(jnp.float32)
     fn = _layer_fn(_skey(s), quant)
     for layer in range(s["layers"]):
-        w = {f"{a}/{b}": blk[a][b][layer] for a in blk for b in blk[a]}
+        w = home({f"{a}/{b}": blk[a][b][layer] for a in blk for b in blk[a]})
         x = fn(x, w)
     return jax.block_until_ready(x)
 
@@ -267,9 +298,10 @@ def read_positions(s, params, seqs, starts, targets, rules, quant=None,
     sel = x[jnp.asarray(rows[:, 0]), jnp.asarray(rows[:, 1])]
     sel = jnp.pad(sel, ((0, Pp - P), (0, 0)))
     del x
-    table = params["embed"]["embedding"] if s["tied"] \
-        else params["embed"]["lm_head"]
-    norm = jnp.asarray(params["final_norm"]["scale"], jnp.float32)
+    home = _home(params)
+    table = home(params["embed"]["embedding"] if s["tied"]
+                 else params["embed"]["lm_head"])
+    norm = home(jnp.asarray(params["final_norm"]["scale"], jnp.float32))
     out = _head_fn(_skey(s), quant, mode)(
         sel.reshape(Pp // HEAD_CHUNK, HEAD_CHUNK, -1), norm, table,
         blocks(tgts), *(blocks([c[j] for c in cols]) for j in range(5)))

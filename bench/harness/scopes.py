@@ -29,7 +29,7 @@ import bisect
 import json
 import sys
 from collections import defaultdict
-from typing import Dict, Iterator, List, Optional, Tuple
+from typing import Dict, Iterable, Iterator, List, Optional, Tuple
 
 try:
     from . import devtrace
@@ -160,14 +160,17 @@ def _program_at(a: float, b: float, progs) -> Optional[int]:
     return None
 
 
-def reduce(tr: dict, paths: Dict[Tuple[int, str], str]) -> Optional[dict]:
+def reduce(tr: dict, paths: Dict[Tuple[int, str], str],
+           devices: Optional[Iterable[int]] = None) -> Optional[dict]:
     """Per-scope device seconds in the decode block program, the program's
     device seconds, device idle seconds per innermost engine span and
-    inside ``engine.step`` spans, and the count of those spans.  ``tr`` is
-    ``devtrace.read``'s, ``paths`` ``op_paths``' of the same trace.  None
-    without device events or without the window span."""
+    inside ``engine.step`` spans, and the count of those spans, each a mean
+    over the planes of ``devices`` (or all).  ``tr`` is ``devtrace.read``'s,
+    ``paths`` ``op_paths``' of the same trace.  None without device events
+    or without the window span."""
     win = [e for e in tr["host"] if e[2] == devtrace.WINDOW_SPAN]
-    if not win or not tr["device"]:
+    dev = devtrace.planes(tr, devices)
+    if not win or not dev:
         return None
     w0, w1 = win[0][0], win[0][1]
     spans = sorted((e for e in tr["host"] if e[2].startswith(SPAN_PREFIX)
@@ -176,12 +179,12 @@ def reduce(tr: dict, paths: Dict[Tuple[int, str], str]) -> Optional[dict]:
     starts = [p[0] for p in pieces]
     steps = devtrace._union([(max(a, w0), min(b, w1))
                              for a, b, n, _ in spans if n == STEP_SPAN])
-    n_dev = len(tr["device"])
+    n_dev = len(dev)
     decode_s, scoped_ops = 0.0, 0
     scope_s: Dict[str, float] = defaultdict(float)
     idle_s, step_idle_s = 0.0, 0.0
     span_idle: Dict[str, float] = defaultdict(float)
-    for lines in tr["device"].values():
+    for lines in dev.values():
         progs = sorted((max(a, w0), min(b, w1), program_id(n))
                        for a, b, n, _ in lines.get(devtrace.MODULES_LINE, [])
                        if devtrace.module_name(n) in DECODE_PROGRAMS

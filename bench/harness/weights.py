@@ -4,7 +4,9 @@ the program's parameter tree for a dense decoder (one stage of stacked
 layers).  ``run.py`` checks the layout against the program before serving.
 
 The same call remakes the same values, which is how the reference gets its
-weights: from the seed, not from the program.
+weights: from the seed, not from the program.  Over several chips each leaf
+is made in place, where the engine keeps it; partitionable threefry, pinned
+here, makes the values bit for bit those of a one-chip make.
 """
 from __future__ import annotations
 
@@ -86,7 +88,7 @@ def _nest(flat: dict) -> dict:
 
 
 @functools.lru_cache(maxsize=None)
-def _maker(items: tuple):
+def _maker(items: tuple, placement):
     import jax
 
     def make(lo, hi):
@@ -97,19 +99,35 @@ def _maker(items: tuple):
                 key, zlib.crc32(path.encode()) & 0x7FFFFFFF), shape, dtype,
                 kind)
             for path, (shape, dtype, kind) in items})
-    return jax.jit(make)
+    if placement is None:
+        return jax.jit(make)
+    return jax.jit(make, out_shardings=jax.tree.unflatten(*placement))
 
 
-def make_params(s: dict, seed: int):
-    """The weights of ``seed`` on the default device."""
+def make_params(s: dict, seed: int, placement=None):
+    """The weights of ``seed``: on the default device, or each leaf where
+    ``placement``, a tree of shardings in the program's layout, puts it."""
+    import jax
     import numpy as np
     items = tuple(sorted(leaf_shapes(s).items()))
-    return _maker(items)(np.uint32(seed & 0xFFFFFFFF),
-                         np.uint32((seed >> 32) & 0xFFFFFFFF))
+    if placement is not None:
+        leaves, tree = jax.tree.flatten(placement)
+        placement = (tree, tuple(leaves))
+    with jax.threefry_partitionable(True):
+        return _maker(items, placement)(
+            np.uint32(seed & 0xFFFFFFFF),
+            np.uint32((seed >> 32) & 0xFFFFFFFF))
 
 
-def param_bytes(s: dict) -> int:
-    """Bytes of every leaf as served."""
+def param_bytes(s: dict, placement=None) -> int:
+    """Bytes of every leaf as served; with ``placement``, those that each
+    chip holds."""
+    import jax
     import numpy as np
-    return sum(int(np.prod(shape)) * (2 if dt == MATRIX_DTYPE else 4)
-               for shape, dt, _ in leaf_shapes(s).values())
+    leaves = leaf_shapes(s)
+    order = jax.tree.leaves(_nest({p: p for p in leaves}))
+    shards = dict(zip(order, jax.tree.leaves(placement))) \
+        if placement is not None else {}
+    return sum(int(np.prod(shards[p].shard_shape(shape) if p in shards
+                           else shape)) * (2 if dt == MATRIX_DTYPE else 4)
+               for p, (shape, dt, _) in leaves.items())

@@ -1,6 +1,7 @@
 """Decode block program's share of the HBM roofline: every weight byte once
-per step plus the K/V bytes of each live row's own context, at peak HBM
-bytes/s, over the program's device time in the trace."""
+per step plus the K/V bytes of each live row's own context, at the cell's
+chips x one chip's peak HBM bytes/s, over the program's device time in the
+trace (a mean over those chips)."""
 from harness import flops, measures
 
 
@@ -13,4 +14,4 @@ def read(run):
         return None
     need = steps * flops.weight_bytes_per_step(run.spec) + \
         sum(flops.kv_bytes(run.spec, k) for k in keys)
-    return 100.0 * need / run.peaks["hbm_bytes_per_s"] / s
+    return 100.0 * need / (run.chips * run.peaks["hbm_bytes_per_s"]) / s

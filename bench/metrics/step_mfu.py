@@ -1,5 +1,6 @@
 """Model FLOPs of every prompt and output token in the traced window over
-(traced seconds x peak bf16 FLOP/s), in percent."""
+(traced seconds x the cell's chips x one chip's peak bf16 FLOP/s), in
+percent."""
 from harness import flops, measures
 
 
@@ -12,4 +13,4 @@ def read(run):
     if not need:
         return None
     return 100.0 * need / (run.trace["window_s"]
-                           * run.peaks["bf16_flops_per_s"])
+                           * (run.chips * run.peaks["bf16_flops_per_s"]))

@@ -36,14 +36,17 @@ def main(argv=None) -> int:
     conf = cell.load_json(cell.BENCH, "configs", args.config + ".json")
     mix = cell.load_json(cell.BENCH, "traffic", args.traffic + ".json")
     enable_compile_cache()
-    cell.device_info(True, 1)
+    chips = int(np.prod(conf["engine"].get("mesh") or (1, 1)))
+    devs = cell.device_info(True, chips)[:chips]
     reduced = next(c["reduced"] for c in cell.load_json(
         cell.ROOT, "BENCHMARK.json")["configs"] if c["name"] == args.config)
     cfg = cell.program_config(conf, reduced)
     spec = weights.spec_of(conf)
-    eng = ServingEngine(cfg, params=weights.make_params(spec, args.seed),
-                        seed=args.seed & 0x7FFFFFFF,
-                        ecfg=EngineConfig(**conf["engine"]))
+    placement = cell.weight_placement(conf, cfg, spec, chips,
+                                      cell.peaks_for(devs[0], True))
+    eng = ServingEngine(
+        cfg, params=weights.make_params(spec, args.seed, placement),
+        seed=args.seed & 0x7FFFFFFF, ecfg=EngineConfig(**conf["engine"]))
     server = Server(eng)
     plans = []
     for r in rates:
